@@ -854,6 +854,9 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated subset of " + ",".join(BENCHES))
     args = ap.parse_args()
+    from repro.cache import enable_compile_cache
+
+    enable_compile_cache()
     RESULTS.mkdir(exist_ok=True)
     names = args.only.split(",") if args.only else list(BENCHES)
     print("name,us_per_call,derived")

@@ -76,6 +76,7 @@ from repro.comm import feedback
 from repro.comm.metrics import RoundTrace
 from repro.obs import NULL_TELEMETRY
 from repro.obs import log as obs_log
+from repro.sharding.rules import client_mesh_scope
 
 # a dropped upload is retried with fresh channel coins; after this many
 # consecutive drops the delivery is forced so the simulation cannot spin
@@ -777,9 +778,10 @@ class PopulationAsyncSession(AsyncSession):
                 mask = jnp.asarray(mvec, self._mask_dtype)
             memory = self.ef_store.gather(padded) if self.ef_store else {}
             _, _, k_codec = self._round_keys(v)
-            outputs[v], mem_out, stats = round_fn(
-                cohort, self._snapshots[v], memory, self.keys[v],
-                self._pack_threat(mask, np.asarray(padded)), k_codec)
+            with client_mesh_scope(self.client_mesh):
+                outputs[v], mem_out, stats = round_fn(
+                    cohort, self._snapshots[v], memory, self.keys[v],
+                    self._pack_threat(mask, np.asarray(padded)), k_codec)
             self._consume_stats(stats)
             if self.ef_store is not None:
                 # real members only: pad rows are frozen duplicates

@@ -69,6 +69,7 @@ from repro.comm.metrics import RoundTrace, Transport, transport_from_traces
 from repro.comm.scheduler import Scheduler, make_scheduler
 from repro.obs import NULL_TELEMETRY
 from repro.obs import log as obs_log
+from repro.sharding.rules import client_mesh_scope, vmap_clients
 
 # payload-name prefix that selects the downlink (server -> client)
 # direction in codec specs and in the byte plan
@@ -425,7 +426,7 @@ class CommRound:
             # residual, or it would compensate into the new basis later.
             self.memory_out[pkey] = self.where_delivered(mem_new, mem)
             return decoded
-        return jax.vmap(codec.roundtrip)(keys, x)
+        return vmap_clients(codec.roundtrip)(keys, x)
 
     def downlink(self, name: str, x: jax.Array,
                  wire_shape: "tuple | None" = None) -> jax.Array:
@@ -924,8 +925,9 @@ class PopulationCommSession(CommSession):
         ids, mask, ck = self.begin_round(t)
         cohort = self._materialize(ids)
         memory = self.ef_store.gather(ids) if self.ef_store else {}
-        self._state, mem_out, stats = round_fn(
-            cohort, self._state, memory, self.keys[t], mask, ck)
+        with client_mesh_scope(self.client_mesh):
+            self._state, mem_out, stats = round_fn(
+                cohort, self._state, memory, self.keys[t], mask, ck)
         self._consume_stats(stats)
         if self.ef_store is not None:
             # real ids only: churn-padded rows duplicate ids[0] and must

@@ -88,6 +88,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.comm.codecs import Codec
+from repro.sharding.rules import vmap_clients
 
 EF_VARIANTS = ("ef21", "ef14")
 
@@ -135,12 +136,12 @@ def compensate(
       ``C(x + e)`` and the client keeps ``(x + e) - C(x + e)``.
     """
     if variant == "ef21":
-        innovation = jax.vmap(codec.roundtrip)(keys, x - mem)
+        innovation = vmap_clients(codec.roundtrip)(keys, x - mem)
         estimate = mem + innovation
         return estimate, estimate
     if variant == "ef14":
         compensated = x + mem
-        decoded = jax.vmap(codec.roundtrip)(keys, compensated)
+        decoded = vmap_clients(codec.roundtrip)(keys, compensated)
         return decoded, compensated - decoded
     raise ValueError(
         f"unknown error-feedback variant {variant!r}; want one of {EF_VARIANTS}")

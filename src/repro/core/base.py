@@ -290,19 +290,14 @@ class History:
 class _ProfilerHook:
     """Opt-in ``jax.profiler`` trace around the first N executed rounds
     (``TelemetryConfig.profile_rounds``). Host-side start/stop only —
-    the traced round functions are untouched."""
+    the traced round functions are untouched. A trace that was asked for
+    and cannot start is an error, not a run without a trace."""
 
     def __init__(self, obs: "TelemetryConfig | None", rounds: int):
         self._remaining = 0
         if obs is None or obs.profile_rounds <= 0 or rounds <= 0:
             return
-        try:
-            jax.profiler.start_trace(obs.profile_dir)
-        except Exception as e:  # profiler backend unavailable: degrade
-            obs_log.warn_with_context(
-                f"jax.profiler trace hook unavailable ({e!r}); continuing "
-                f"without a device trace", profile_dir=obs.profile_dir)
-            return
+        jax.profiler.start_trace(obs.profile_dir)
         self._remaining = min(int(obs.profile_rounds), rounds)
         obs_log.info("jax.profiler trace started",
                      profile_dir=obs.profile_dir, rounds=self._remaining)

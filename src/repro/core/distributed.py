@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
-
 from repro.core.losses import Objective
 from repro.core.sketch_policy import SketchPolicy, as_policy
 
@@ -109,7 +107,7 @@ class DistributedFLeNS:
         spec_y = P(self._axes() or None)
         rep = P(None, None)
 
-        wrapped = shard_map(
+        wrapped = jax.shard_map(
             body,
             mesh=self.mesh,
             in_specs=(spec_data, spec_y, rep, rep, P(None)),

@@ -41,6 +41,7 @@ from repro.core.sketch_policy import (
     as_policy,
     loss_effective_dimension,
 )
+from repro.sharding.rules import vmap_clients
 
 
 # lower bound of the guard's backtracking trust scale: rejects halve the
@@ -192,8 +193,8 @@ class FLeNS(FederatedOptimizer):
             bj = s.apply(aj)  # A_j S^T : (n_shard, k)
             return bj.T @ bj  # (k, k), + lam S S^T added after aggregation
 
-        h_sk = jax.vmap(client_sketch)(a)  # (m, k, k)
-        sg = jax.vmap(s.apply)(gs)  # (m, k)
+        h_sk = vmap_clients(client_sketch)(a)  # (m, k, k)
+        sg = vmap_clients(s.apply)(gs)  # (m, k)
 
         # uplink: the k×k sketched Hessian (symmetric — sympack applies)
         # and the sketched gradient flow through the transport codecs.

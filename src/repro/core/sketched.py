@@ -27,6 +27,7 @@ from repro.core.sketch_policy import (
     as_policy,
     loss_effective_dimension,
 )
+from repro.sharding.rules import vmap_clients
 
 
 class FedNS(FederatedOptimizer):
@@ -81,7 +82,7 @@ class FedNS(FederatedOptimizer):
             # S acts on the data axis: (k, n) @ (n, M) -> (k, M)
             return s.apply(aj.T).T
 
-        sa = jax.vmap(client)(a, keys)  # (m, k, M)
+        sa = vmap_clients(client)(a, keys)  # (m, k, M)
         # EF eligibility flows from the schedule: a fresh data-axis
         # basis makes cross-round memory meaningless, a fixed/rotating
         # one keeps the (k, M) payload in a stable coordinate system —

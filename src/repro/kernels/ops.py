@@ -32,6 +32,13 @@ impl is actually selected.
 NOTE: resolution happens at Python trace time. Inside an already-traced
 jit cache entry the choice is baked in; set the env var / default before
 the first call (CI does).
+
+Under a current mesh (``jax.set_mesh``, as population rounds over a
+``client_mesh`` run) the compiler would have to partition a Pallas
+kernel, which it cannot; the compiled kernel then runs whole on every
+device, inside a ``shard_map`` with replicated operands. Per-client
+calls avoid that replication by mapping each device's own clients
+(``repro.sharding.rules.vmap_clients``).
 """
 from __future__ import annotations
 
@@ -41,6 +48,7 @@ import os
 from typing import Callable
 
 import jax
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref
 
@@ -132,7 +140,16 @@ def get_impl(op: str, impl: str) -> Callable:
 
 
 def _dispatch(op: str, impl: str | None) -> Callable:
-    return get_impl(op, resolve_impl(impl))
+    resolved = resolve_impl(impl)
+    fn = get_impl(op, resolved)
+    mesh = jax.sharding.get_abstract_mesh()
+    if resolved != "pallas" or mesh.size <= 1 or mesh.are_all_axes_manual:
+        return fn
+
+    def on_every_device(*arrays, **static):
+        return jax.shard_map(functools.partial(fn, **static), in_specs=P(),
+                             out_specs=P(), check_vma=False)(*arrays)
+    return on_every_device
 
 
 # ---------------------------------------------------------------------------
@@ -156,14 +173,14 @@ def srht_apply_t(y: jax.Array, signs: jax.Array, rows: jax.Array,
                  dim: int, *, impl: str | None = None) -> jax.Array:
     """Fused SRHT transpose: scatter -> FWHT -> sign-flip -> restrict.
     y (..., k) -> (..., dim)."""
-    return _dispatch("srht_apply_t", impl)(y, signs, rows, dim)
+    return _dispatch("srht_apply_t", impl)(y, signs, rows, dim=dim)
 
 
 def topk_mask(x: jax.Array, kept: int, *,
               impl: str | None = None) -> jax.Array:
     """Keep the ``kept`` largest-|.| entries of ``x`` (dense mask; ties
     broken by lowest flat index, as ``jax.lax.top_k``)."""
-    return _dispatch("topk_mask", impl)(x, kept)
+    return _dispatch("topk_mask", impl)(x, kept=kept)
 
 
 def qint8_roundtrip(x: jax.Array, u: jax.Array, *,

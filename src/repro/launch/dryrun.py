@@ -73,10 +73,8 @@ def run_one(arch: str, shape: str, mesh_kind: str, out_dir: pathlib.Path,
             compiled = lowered.compile()
             t_compile = time.perf_counter() - t0 - t_lower
 
-            from repro.compat import cost_analysis
-
             mem = compiled.memory_analysis()
-            cost = cost_analysis(compiled)
+            cost = compiled.cost_analysis()
             hlo = compiled.as_text()
         coll = collective_stats(hlo)
 

@@ -33,7 +33,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import cost_analysis, shard_map
 from repro.core.base import root_key
 
 
@@ -142,7 +141,7 @@ def lower_method(method: str, mesh, dim: int, k: int, n_per_client: int,
         NamedSharding(mesh, P()),
     )
 
-    wrapped = shard_map(
+    wrapped = jax.shard_map(
         lambda X, y, w, signs, rows: fn(X, y, w[0], signs[0], rows[0])[None],
         mesh=mesh,
         in_specs=(P(("pod", "data"), None), P(("pod", "data")), P(None),
@@ -163,7 +162,7 @@ def lower_method(method: str, mesh, dim: int, k: int, n_per_client: int,
         "theory_wire_floats_per_client": wire,
         "collective_bytes_per_device": coll["total_bytes"],
         "collectives": coll["per_kind"],
-        "flops_per_device": float(cost_analysis(compiled).get("flops", 0.0)),
+        "flops_per_device": float(compiled.cost_analysis().get("flops", 0.0)),
     }
 
 

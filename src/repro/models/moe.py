@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.models.common import ModelConfig, dense_init, residual_out_init
 from repro.sharding.ctx import get_mesh
 
@@ -190,7 +189,7 @@ def moe_apply(params, x, cfg: ModelConfig, *, capacity: int | None = None):
                 drop = jax.lax.pmean(drop, "pod")
             return o_mine.reshape(bl, tl, dl), aux, drop
 
-        out, aux, drop = shard_map(
+        out, aux, drop = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(
@@ -221,7 +220,7 @@ def moe_apply(params, x, cfg: ModelConfig, *, capacity: int | None = None):
                 drop = jax.lax.pmean(drop, ax)
             return o.reshape(bl, tl, dl), aux, drop
 
-        out, aux, drop = shard_map(
+        out, aux, drop = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(

@@ -17,6 +17,8 @@ zoo — the single place where layout policy lives.
 """
 from __future__ import annotations
 
+import contextlib
+
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -180,17 +182,29 @@ def tree_shardings(mesh: Mesh, tree, rule, **kw):
 
 # -- federated cohort rules ---------------------------------------------------
 
+def _client_axis(mesh) -> "str | None":
+    """The first client-capable axis of ``mesh`` (``clients``, else
+    ``data``), or None."""
+    axes = [a for a in ("clients", "data") if a in mesh.axis_names]
+    return axes[0] if axes else None
+
+
 def cohort_spec(mesh: Mesh, leaf) -> P:
     """PartitionSpec for one cohort-stacked array: shard the leading
     (client) axis over the first available client-capable mesh axis,
-    replicate everything else. Divisibility-guarded like every other
-    rule — a cohort that doesn't divide the mesh falls back to
-    replication rather than erroring."""
-    axes = tuple(a for a in ("clients", "data") if a in mesh.axis_names)[:1]
-    if not axes or leaf.ndim == 0:
+    replicate everything else. A cohort whose client axis does not
+    divide that mesh axis raises: replicating it instead would run every
+    client on every device while the caller asked for a split."""
+    axis = _client_axis(mesh)
+    if axis is None or leaf.ndim == 0:
         return P()
-    return _guard(mesh, leaf.shape,
-                  (axes[0],) + (None,) * (leaf.ndim - 1))
+    ways = mesh.shape[axis]
+    if leaf.shape[0] % ways:
+        raise ValueError(
+            f"a cohort of {leaf.shape[0]} clients does not divide the "
+            f"{ways}-way {axis!r} mesh axis; use a cohort size that is "
+            f"a multiple of {ways}")
+    return P(axis)
 
 
 def shard_cohort(mesh: Mesh, cohort):
@@ -200,11 +214,35 @@ def shard_cohort(mesh: Mesh, cohort):
     This is how a population-mode round spreads over devices: the jitted
     round is vmapped over the client axis, so GSPMD partitions every
     per-client computation along the mesh and the server aggregation
-    becomes a cross-device reduction — no shard_map rewrite of the round
-    needed. A 1-device mesh is the identity placement.
+    becomes a cross-device reduction. The sessions trace the round under
+    ``client_mesh_scope`` so that ``vmap_clients`` maps the per-client
+    kernel calls device by device. A 1-device mesh is the identity
+    placement.
     """
     return jax.tree_util.tree_map(
         lambda leaf: jax.device_put(
             leaf, NamedSharding(mesh, cohort_spec(mesh, leaf))),
         cohort,
     )
+
+
+def client_mesh_scope(mesh: "Mesh | None"):
+    """Context in which a round over a ``shard_cohort`` cohort is traced
+    and run: sets ``mesh`` as JAX's current mesh (nothing without one)."""
+    return contextlib.nullcontext() if mesh is None else jax.set_mesh(mesh)
+
+
+def vmap_clients(fn):
+    """``jax.vmap(fn)`` over the leading client axis of every argument.
+
+    Under a current mesh with a client axis (``client_mesh_scope``) each
+    device maps only its own clients, inside a ``shard_map``: the
+    compiler cannot partition a Pallas kernel, so a vmapped kernel over
+    a sharded client axis would not compile for the chip. Without such
+    a mesh this is exactly ``jax.vmap(fn)``.
+    """
+    axis = _client_axis(jax.sharding.get_abstract_mesh())
+    if axis is None:
+        return jax.vmap(fn)
+    return jax.shard_map(jax.vmap(fn), in_specs=P(axis), out_specs=P(axis),
+                         check_vma=False)

@@ -46,6 +46,19 @@ def test_topk_tie_breaking_matches_lax_top_k():
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+@pytest.mark.parametrize("kept", [1, 37, 500, 1999])
+def test_topk_many_ties_lowest_index_wins(kept):
+    """A payload wider than one lane tile with few distinct magnitudes:
+    the lane-index bisection keeps exactly the lowest-index ties, as
+    jax.lax.top_k does."""
+    levels = jnp.asarray([0.0, 0.5, -0.5, 1.0, -1.0, 2.0], jnp.float32)
+    idx = jax.random.randint(jax.random.PRNGKey(kept), (2000,), 0, 6)
+    x = levels[idx]
+    want = kops.topk_mask(x, kept, impl="ref")
+    got = kops.topk_mask(x, kept, impl="interpret")
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_topk_all_zero_payload():
     x = jnp.zeros((5, 5), jnp.float32)
     got = kops.topk_mask(x, 3, impl="interpret")

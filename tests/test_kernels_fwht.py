@@ -77,3 +77,13 @@ def test_ops_dispatch_reference_matches_interpret():
     a = fwht(x, impl="reference")
     b = fwht(x, impl="interpret")
     np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+
+
+def test_width_above_kernel_limit_raises():
+    """A width the kernels cannot hold in VMEM is refused by name, on
+    every path — never served by a silent fallback."""
+    from repro.kernels.fwht import MAX_WIDTH
+
+    x = jnp.ones((1, 2 * MAX_WIDTH), jnp.float32)
+    with pytest.raises(ValueError, match="MAX_WIDTH=65536"):
+        fwht_pallas(x, interpret=True)

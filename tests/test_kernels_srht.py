@@ -201,3 +201,50 @@ def test_default_impl_none_clears_config(monkeypatch):
         kops.set_default_impl(None)
     if jax.default_backend() != "tpu":
         assert kops.resolve_impl() == "ref"
+
+
+@pytest.mark.parametrize("transpose", [False, True])
+def test_srht_index_select_path_parity(transpose):
+    """Above the one-hot budget (n·k·4 bytes > ONEHOT_MAX_BYTES) the
+    subsample runs by index outside the kernel; same values as ref."""
+    from repro.kernels.srht import ONEHOT_MAX_BYTES
+
+    dim, k = 3000, 1024
+    n = 4096
+    assert n * k * 4 > ONEHOT_MAX_BYTES
+    s = _srht(dim, k=k)
+    if transpose:
+        y = jax.random.normal(jax.random.PRNGKey(13), (3, k), jnp.float32)
+        want = kops.srht_apply_t(y, s.signs, s.rows, dim, impl="ref")
+        got = kops.srht_apply_t(y, s.signs, s.rows, dim, impl="interpret")
+    else:
+        x = jax.random.normal(jax.random.PRNGKey(13), (3, dim), jnp.float32)
+        want = kops.srht_apply(x, s.signs, s.rows, impl="ref")
+        got = kops.srht_apply(x, s.signs, s.rows, impl="interpret")
+    assert got.shape == want.shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               **_tol(n, jnp.float32))
+
+
+def test_compiled_path_refuses_64bit_operands():
+    """The compiled kernels never cast a float64 operand silently: the
+    wrapper raises before Mosaic sees it, naming the dtype."""
+    from repro.kernels.codec_kernels import (
+        qint8_roundtrip_pallas,
+        topk_mask_pallas,
+    )
+    from repro.kernels.fwht import fwht_pallas
+    from repro.kernels.srht import srht_apply_pallas, srht_apply_t_pallas
+
+    s = _srht(37, dtype=jnp.float64)
+    x = jnp.ones((2, 37), jnp.float64)
+    calls = [
+        lambda: fwht_pallas(jnp.ones((2, 64), jnp.float64)),
+        lambda: srht_apply_pallas(x, s.signs, s.rows),
+        lambda: srht_apply_t_pallas(jnp.ones((2, s.k)), s.signs, s.rows, 37),
+        lambda: topk_mask_pallas(x, 3),
+        lambda: qint8_roundtrip_pallas(x, x),
+    ]
+    for call in calls:
+        with pytest.raises(TypeError, match="float64"):
+            call()

@@ -101,6 +101,21 @@ def test_null_sink_bit_identical(small_problem, comm_fn, tmp_path):
     assert null.telemetry is not None
 
 
+def test_profiler_hook_raises_when_trace_cannot_start(small_problem,
+                                                      tmp_path):
+    """A trace that was asked for and cannot start is an error, never a
+    run that quietly has no device trace."""
+    prob, w0, w_star = small_problem
+    jax.profiler.start_trace(str(tmp_path / "held"))
+    try:
+        with pytest.raises(RuntimeError, match="already been started"):
+            run_rounds(_flens(), prob, w0, w_star, rounds=2,
+                       obs=TelemetryConfig(profile_rounds=1,
+                                           profile_dir=str(tmp_path / "p")))
+    finally:
+        jax.profiler.stop_trace()
+
+
 def test_summary_compile_exec_split(small_problem):
     """Exactly one compile round per jit variant; wall-clock splits into
     compile_s (first call, trace+compile) and exec_s (steady state)."""
