@@ -437,21 +437,45 @@ class AsyncSession:
     def step(self, round_fn) -> Any:
         """Run the event simulation up to the next server commit and
         return the committed state. ``round_fn(state, memory, key, mask,
-        codec_key) -> (state, memory)`` is the jitted optimizer round."""
-        commit_time = self._pump()
-        committed, self._buffer = self._buffer, []
-        if self.obs.enabled:
-            self._observe_commit(committed, commit_time)
+        codec_key) -> (state, memory)`` is the jitted optimizer round,
+        launched once per base model version in the commit."""
+        from repro.comm.config import count_round_calls
 
-        # group arrivals by the model version they computed on
-        groups: Dict[int, List[tuple]] = {}
-        for client, version, straggler, _ in committed:
-            groups.setdefault(version, []).append((client, straggler))
-        order = sorted(groups, reverse=True)  # freshest first
+        span = self.obs.trace.span
+        with span("session.pump"):
+            commit_time = self._pump()
+            committed, self._buffer = self._buffer, []
+            # group arrivals by the model version they computed on
+            groups: Dict[int, List[int]] = {}
+            for client, version, _, _ in committed:
+                groups.setdefault(version, []).append(client)
+            order = sorted(groups, reverse=True)  # freshest first
 
-        outputs: Dict[int, Any] = {}
-        for v in order:
-            members = [c for c, _ in groups[v]]
+        outputs = {v: self._run_group(round_fn, v, groups[v]) for v in order}
+        with span("session.aggregate"):
+            state_new = self._combine(groups, order, outputs)
+        with span("session.account"):
+            if self.obs.enabled:
+                self._observe_commit(committed, commit_time)
+            self._record_trace(committed, commit_time)
+            count_round_calls(self.obs, len(order))
+        self.version += 1
+        self.server_clock = commit_time
+        self._snapshots[self.version] = state_new
+        with span("session.gc"):
+            self._gc_snapshots()
+        with span("session.dispatch"):
+            self._release(committed)
+            self._dispatch_cohort(
+                sorted({c for c, _, _, _ in committed} | self._idle),
+                now=commit_time)
+        return state_new
+
+    def _run_group(self, round_fn, v: int, members: "list[int]") -> Any:
+        """One jitted round from the snapshot of version ``v`` over the
+        commit's ``members`` that computed on it; returns its state."""
+        span = self.obs.trace.span
+        with span("session.schedule"):
             if self.lockstep:
                 mask = None
             else:
@@ -459,11 +483,17 @@ class AsyncSession:
                 mvec[members] = 1.0
                 mask = jnp.asarray(mvec, self._mask_dtype)
             _, _, k_codec = self._round_keys(v)
-            outputs[v], self.ef_memory, stats = round_fn(
-                self._snapshots[v], self.ef_memory, self.keys[v],
-                self._pack_threat(mask), k_codec)
+            mask = self._pack_threat(mask)
+        with span("launch"):
+            out, self.ef_memory, stats = round_fn(
+                self._snapshots[v], self.ef_memory, self.keys[v], mask,
+                k_codec)
+        with span("session.stats"):
             self._consume_stats(stats)
+        return out
 
+    def _combine(self, groups, order, outputs) -> Any:
+        """The committed state from the groups' round outputs."""
         fresh = order[0]
         eta = float(self.config.server_lr)
         if len(order) == 1 and fresh == self.version and eta == 1.0:
@@ -471,50 +501,41 @@ class AsyncSession:
             # the next state (no delta arithmetic — preserves sync
             # bit-exactness; the staleness weight is 1 at tau=0 by
             # convention)
-            state_new = outputs[fresh]
-        else:
-            # c_g = eta_s * staleness(tau_g) * P_g / sum_h P_h:
-            # participation mass is renormalized over the commit (as the
-            # sync driver renormalizes partial cohorts) but staleness
-            # DAMPS the step rather than being renormalized away — an
-            # all-stale commit under "inverse" moves the model by
-            # 1/(1+tau) of its delta, and a weight of exactly 0
-            # contributes exactly nothing. The FedBuff-style global
-            # server learning rate eta_s scales every committed delta on
-            # top (eta_s = 1 is bit-identical to not having the knob).
-            p_mass = {
-                v: float(self.client_weights[[c for c, _ in groups[v]]].sum())
-                for v in order
-            }
-            p_total = sum(p_mass.values())
-            w_cur = self._snapshots[self.version]["w"]
-            w_new = w_cur
-            for v in order:
-                c = (eta * self._staleness(float(self.version - v))
-                     * p_mass[v] / p_total)
-                delta = outputs[v]["w"] - self._snapshots[v]["w"]
-                w_new = w_new + c * delta
-            # auxiliary state rides the freshest cohort's round when that
-            # cohort is current; otherwise the current state is kept and
-            # only the model moves (stale aux must not overwrite fresher)
-            base = (outputs[fresh] if fresh == self.version
-                    else self._snapshots[self.version])
-            state_new = dict(base)
-            state_new["w"] = w_new
-
-        self._record_trace(committed, commit_time)
-        self.version += 1
-        self.server_clock = commit_time
-        self._snapshots[self.version] = state_new
-        self._gc_snapshots()
-        self._dispatch_cohort(
-            sorted({c for c, _, _, _ in committed} | self._idle),
-            now=commit_time)
+            return outputs[fresh]
+        # c_g = eta_s * staleness(tau_g) * P_g / sum_h P_h:
+        # participation mass is renormalized over the commit (as the
+        # sync driver renormalizes partial cohorts) but staleness
+        # DAMPS the step rather than being renormalized away — an
+        # all-stale commit under "inverse" moves the model by
+        # 1/(1+tau) of its delta, and a weight of exactly 0
+        # contributes exactly nothing. The FedBuff-style global
+        # server learning rate eta_s scales every committed delta on
+        # top (eta_s = 1 is bit-identical to not having the knob).
+        p_mass = {v: float(self.client_weights[groups[v]].sum())
+                  for v in order}
+        p_total = sum(p_mass.values())
+        w_new = self._snapshots[self.version]["w"]
+        for v in order:
+            c = (eta * self._staleness(float(self.version - v))
+                 * p_mass[v] / p_total)
+            delta = outputs[v]["w"] - self._snapshots[v]["w"]
+            w_new = w_new + c * delta
+        # auxiliary state rides the freshest cohort's round when that
+        # cohort is current; otherwise the current state is kept and
+        # only the model moves (stale aux must not overwrite fresher)
+        base = (outputs[fresh] if fresh == self.version
+                else self._snapshots[self.version])
+        state_new = dict(base)
+        state_new["w"] = w_new
         return state_new
 
+    def _release(self, committed) -> None:
+        """Hook: the committed clients landed (the dense driver tracks
+        them through the dispatch set it passes)."""
+
     def _observe_commit(self, committed, commit_time: float) -> None:
-        """Populate commit-time telemetry (host-side, before aggregation;
-        only called when telemetry is enabled)."""
+        """Populate commit-time telemetry (host-side, after the commit's
+        rounds; only called when telemetry is enabled)."""
         mt = self.obs.metrics
         mt.histogram("commit_buffer_depth").observe(len(committed))
         mt.histogram("inflight_depth").observe(len(self._heap))
@@ -744,83 +765,50 @@ class PopulationAsyncSession(AsyncSession):
             self.ef_store.retire(departed)
 
     # -- one server commit ---------------------------------------------------
-    def step(self, round_fn) -> Any:
-        """Population-mode commit: groups materialize their members'
-        shards on demand. ``round_fn(cohort, state, memory, key, mask,
+    def _run_group(self, round_fn, v: int, members: "list[int]") -> Any:
+        """Population-mode group round: the members' shards materialize
+        on demand. ``round_fn(cohort, state, memory, key, mask,
         codec_key) -> (state, memory)`` is the jitted cohort round."""
-        commit_time = self._pump()
-        committed, self._buffer = self._buffer, []
-        if self.obs.enabled:
-            self._observe_commit(committed, commit_time)
-
-        groups: Dict[int, List[tuple]] = {}
-        for client, version, straggler, _ in committed:
-            groups.setdefault(version, []).append((client, straggler))
-        order = sorted(groups, reverse=True)  # freshest first
-
-        outputs: Dict[int, Any] = {}
-        for v in order:
-            members = [c for c, _ in groups[v]]
-            n_real = len(members)
-            # fixed-width cohort: pad with the first member's id under a
-            # zero delivery mask, so every group reuses one jaxpr
-            padded = members + [members[0]] * (self.cohort_size - n_real)
-            cohort = self.population.materialize(np.asarray(padded))
+        span = self.obs.trace.span
+        n_real = len(members)
+        # fixed-width cohort: pad with the first member's id under a
+        # zero delivery mask, so every group reuses one jaxpr
+        padded = np.asarray(members + [members[0]] * (self.cohort_size
+                                                      - n_real))
+        with span("session.materialize"):
+            cohort = self.population.materialize(padded)
             if self.client_mesh is not None:
                 from repro.sharding.rules import shard_cohort
 
                 cohort = shard_cohort(self.client_mesh, cohort)
+            memory = self.ef_store.gather(padded) if self.ef_store else {}
+        with span("session.schedule"):
             if self.lockstep:
                 mask = None
             else:
                 mvec = np.zeros(self.cohort_size)
                 mvec[:n_real] = 1.0
                 mask = jnp.asarray(mvec, self._mask_dtype)
-            memory = self.ef_store.gather(padded) if self.ef_store else {}
             _, _, k_codec = self._round_keys(v)
-            with client_mesh_scope(self.client_mesh):
-                outputs[v], mem_out, stats = round_fn(
-                    cohort, self._snapshots[v], memory, self.keys[v],
-                    self._pack_threat(mask, np.asarray(padded)), k_codec)
+            mask = self._pack_threat(mask, padded)
+        with span("launch"), client_mesh_scope(self.client_mesh):
+            out, mem_out, stats = round_fn(
+                cohort, self._snapshots[v], memory, self.keys[v], mask,
+                k_codec)
+        with span("session.stats"):
             self._consume_stats(stats)
-            if self.ef_store is not None:
+        if self.ef_store is not None:
+            with span("session.materialize"):
                 # real members only: pad rows are frozen duplicates
                 self.ef_store.scatter(members, mem_out)
+        return out
 
-        fresh = order[0]
-        eta = float(self.config.server_lr)
-        if len(order) == 1 and fresh == self.version and eta == 1.0:
-            state_new = outputs[fresh]
-        else:
-            # same commit combination as the dense driver: staleness
-            # damps, participation mass renormalizes over the commit
-            p_mass = {
-                v: float(self.client_weights[[c for c, _ in groups[v]]].sum())
-                for v in order
-            }
-            p_total = sum(p_mass.values())
-            w_cur = self._snapshots[self.version]["w"]
-            w_new = w_cur
-            for v in order:
-                c = (eta * self._staleness(float(self.version - v))
-                     * p_mass[v] / p_total)
-                delta = outputs[v]["w"] - self._snapshots[v]["w"]
-                w_new = w_new + c * delta
-            base = (outputs[fresh] if fresh == self.version
-                    else self._snapshots[self.version])
-            state_new = dict(base)
-            state_new["w"] = w_new
-
-        self._record_trace(committed, commit_time)
+    def _release(self, committed) -> None:
+        """The committed clients return to the anonymous pool, and the
+        new version's cohort draws its coins afresh."""
         for client, _, _, _ in committed:
             self._in_flight.discard(client)
-        self.version += 1
         self._attempt = 0
-        self.server_clock = commit_time
-        self._snapshots[self.version] = state_new
-        self._gc_snapshots()
-        self._dispatch_cohort((), now=commit_time)
-        return state_new
 
     def _record_trace(self, committed, commit_time: float) -> None:
         down = dict(self._pending_down)

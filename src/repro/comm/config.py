@@ -253,6 +253,14 @@ class CommConfig:
         return dyn.channel.at(self.channel, t)
 
 
+def count_round_calls(obs, n: int) -> None:
+    """Record the ``n`` jitted round launches of the round executing: the
+    ``round_calls`` field of its record and the counter of that name."""
+    if obs.enabled:
+        obs.metrics.counter("round_calls").inc(n)
+        obs.annotate(round_calls=n)
+
+
 def apply_churn(session, t: int) -> "np.ndarray | None":
     """Shared churn bookkeeping for every comm session at round/version
     ``t``: returns the eligible id array (or ``None`` without churn),
@@ -653,12 +661,18 @@ class CommSession:
     def step(self, round_fn) -> Any:
         """One lock-step round: draw cohort, execute, account."""
         t = self._t
-        mask, ck = self.begin_round(t)
-        self._state, self.ef_memory, stats = round_fn(
-            self._state, self.ef_memory, self.keys[t], mask, ck)
-        self._consume_stats(stats)
-        self.end_round()
+        span = self.obs.trace.span
+        with span("session.schedule"):
+            mask, ck = self.begin_round(t)
+        with span("launch"):
+            self._state, self.ef_memory, stats = round_fn(
+                self._state, self.ef_memory, self.keys[t], mask, ck)
+        with span("session.stats"):
+            self._consume_stats(stats)
+        with span("session.account"):
+            self.end_round()
         self._t += 1
+        count_round_calls(self.obs, 1)
         return self._state
 
     def _consume_stats(self, stats: Dict[str, Any]) -> None:
@@ -922,19 +936,26 @@ class PopulationCommSession(CommSession):
         pytree argument, so one jaxpr serves every cohort).
         """
         t = self._t
-        ids, mask, ck = self.begin_round(t)
-        cohort = self._materialize(ids)
-        memory = self.ef_store.gather(ids) if self.ef_store else {}
-        with client_mesh_scope(self.client_mesh):
+        span = self.obs.trace.span
+        with span("session.schedule"):
+            ids, mask, ck = self.begin_round(t)
+        with span("session.materialize"):
+            cohort = self._materialize(ids)
+            memory = self.ef_store.gather(ids) if self.ef_store else {}
+        with span("launch"), client_mesh_scope(self.client_mesh):
             self._state, mem_out, stats = round_fn(
                 cohort, self._state, memory, self.keys[t], mask, ck)
-        self._consume_stats(stats)
+        with span("session.stats"):
+            self._consume_stats(stats)
         if self.ef_store is not None:
-            # real ids only: churn-padded rows duplicate ids[0] and must
-            # not race its real row on scatter
-            self.ef_store.scatter(ids[:self._pending_real], mem_out)
-        self.end_round()
+            with span("session.materialize"):
+                # real ids only: churn-padded rows duplicate ids[0] and
+                # must not race its real row on scatter
+                self.ef_store.scatter(ids[:self._pending_real], mem_out)
+        with span("session.account"):
+            self.end_round()
         self._t += 1
+        count_round_calls(self.obs, 1)
         return self._state
 
     def _retire_ef(self, departed: np.ndarray) -> None:

@@ -48,6 +48,7 @@ from repro.comm.config import (
     CommConfig,
     CommSession,
     PopulationCommSession,
+    count_round_calls,
     plan_bytes,
     probe_round,
 )
@@ -116,7 +117,6 @@ class NullSession(Session):
                     f"axis falls back to the per-optimizer float-count "
                     f"formulas for this run (these can undercount the "
                     f"wire)", round=self._t, variant=sig)
-                self.obs.metrics.counter("plan_probe_fallbacks").inc()
             self._plans[sig] = plan
         plan = self._plans[sig]
         if plan is not None:
@@ -128,13 +128,12 @@ class NullSession(Session):
         return NULL_COMM
 
     def step(self, round_fn) -> Any:
-        self._state, _, _ = round_fn(self._state, {}, self.keys[self._t],
-                                     None, None)
+        with self.obs.trace.span("launch"):
+            self._state, _, _ = round_fn(self._state, {},
+                                         self.keys[self._t], None, None)
         self._per_round.append(self._formula)
         self._t += 1
-        if self.obs.enabled:
-            self.obs.metrics.counter("formula_bytes").inc(self._formula)
-            self.obs.annotate(formula_bytes=self._formula)
+        count_round_calls(self.obs, 1)
         return self._state
 
     def finalize(self) -> Transport:

@@ -288,19 +288,25 @@ class History:
 
 
 class _ProfilerHook:
-    """Opt-in ``jax.profiler`` trace around the first N executed rounds
-    (``TelemetryConfig.profile_rounds``). Host-side start/stop only —
-    the traced round functions are untouched. A trace that was asked for
-    and cannot start is an error, not a run without a trace."""
+    """Opt-in ``jax.profiler`` trace of N rounds
+    (``TelemetryConfig.profile_rounds``), from the first round that does
+    not compile: the trace holds steady rounds, not the compile. Host-side
+    start/stop only — the traced round functions are untouched. A trace
+    that was asked for and cannot start is an error, not a run without a
+    trace."""
 
-    def __init__(self, obs: "TelemetryConfig | None", rounds: int):
+    def __init__(self, obs: "TelemetryConfig | None"):
+        self._dir = obs.profile_dir if obs is not None else None
+        self._wanted = max(int(obs.profile_rounds), 0) if obs else 0
         self._remaining = 0
-        if obs is None or obs.profile_rounds <= 0 or rounds <= 0:
-            return
-        jax.profiler.start_trace(obs.profile_dir)
-        self._remaining = min(int(obs.profile_rounds), rounds)
-        obs_log.info("jax.profiler trace started",
-                     profile_dir=obs.profile_dir, rounds=self._remaining)
+
+    def before_round(self, t: int, compile_expected: bool) -> None:
+        if self._wanted and not compile_expected:
+            jax.profiler.start_trace(self._dir)
+            self._remaining, self._wanted = self._wanted, 0
+            obs_log.info("jax.profiler trace started",
+                         profile_dir=self._dir, first_round=t,
+                         rounds=self._remaining)
 
     def after_round(self) -> None:
         if self._remaining > 0:
@@ -348,11 +354,13 @@ def run_rounds(
 
     ``obs=TelemetryConfig(...)`` turns on the ``repro.obs`` telemetry
     layer: host-side phase spans around the jit boundaries
-    (schedule / client round / account / retrace / eval — never inside
-    traced code), a compile-vs-execute wall-clock split (the first call
-    of each jitted round variant is billed as compile), session metrics
-    (bytes, deliveries, staleness distribution, async queue depths), and
-    the async flight recorder. The default (``obs=None``) is the shared
+    (``step`` with the session's ``session.*`` phases, ``launch`` and
+    ``wait`` under it, ``eval`` — never inside traced code, and on the
+    clock of any ``jax.profiler`` trace), a compile-vs-execute
+    wall-clock split (the first call of each jitted round variant is
+    billed as compile), session metrics (bytes, deliveries, jitted round
+    launches, staleness distribution, async queue depths), and the async
+    flight recorder. The default (``obs=None``) is the shared
     no-op telemetry: zero overhead and bit-identical trajectories —
     instrumentation can never perturb the optimization (tested). The
     run summary lands on ``History.telemetry``.
@@ -445,15 +453,17 @@ def run_rounds(
     # that variant's byte plan so per-round traces bill the true sizes
     round_fns: Dict[Any, Any] = {}
     retraces = telemetry.metrics.counter("variant_retraces")
-    profiler = _ProfilerHook(obs, rounds)
+    profiler = _ProfilerHook(obs)
     sig_prev = object()  # sentinel: no signature compares equal to it
     t0 = time.perf_counter()
     for t in range(rounds):
         sig = opt.round_signature(t, state)
+        compile_expected = sig not in round_fns
+        profiler.before_round(t, compile_expected)
         # host wall-clock attribution wraps the jit BOUNDARIES only:
         # begin_variant/step/eval run exactly the code they always ran —
         # the spans never reach inside traced functions
-        with telemetry.round(t, compile_expected=sig not in round_fns):
+        with telemetry.round(t, compile_expected=compile_expected):
             if sig != sig_prev:
                 with telemetry.trace.span("begin_variant"):
                     session.begin_variant(sig, trace_with(state))
@@ -468,7 +478,8 @@ def run_rounds(
                 if telemetry.enabled:
                     # honest span timing: settle async dispatch before
                     # the host timer stops (device values are unchanged)
-                    jax.block_until_ready(state["w"])
+                    with telemetry.trace.span("wait"):
+                        jax.block_until_ready(state["w"])
             with telemetry.trace.span("eval"):
                 losses.append(float(loss_fn(state["w"])))
                 gnorms.append(float(jnp.linalg.norm(grad_fn(state["w"]))))

@@ -15,7 +15,8 @@ jsonl sink alike).
 Record stream (what a sink sees, one dict per record):
 
   * per round:  ``{"type": "round", "round": t, "wall_s", "compile",
-                  "phases": {name: seconds}, ...session annotations}``
+                  "phases": {name: seconds}, "round_calls",
+                  ...session annotations}``
   * flight:     ``{"type": "flight", ...event}`` (async runs, dumped at
                   finalize, ring-truncated to the most recent events)
   * summary:    ``{"type": "summary", "compile_s", "exec_s",
@@ -30,6 +31,8 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import time
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs.flight import NULL_FLIGHT, FlightRecorder
 from repro.obs.metrics import NULL_METRICS, MetricsRegistry
@@ -50,9 +53,10 @@ class TelemetryConfig:
     ``"jsonl:<path>"`` (appends; runs are distinguished by ``label``).
     ``flight_capacity`` — ring size of the async flight recorder.
     ``profile_rounds`` — opt-in ``jax.profiler`` trace hook: capture a
-    device/host trace around the FIRST N executed rounds (0 = off) into
-    ``profile_dir``. This is the only knob that touches jax at all, and
-    it wraps rounds from the host — traced code is never modified.
+    device/host trace of N rounds (0 = off) into ``profile_dir``,
+    starting at the first round that does not compile, so the trace
+    holds steady rounds. It wraps rounds from the host — traced code is
+    never modified.
     """
 
     sink: str = "null"
@@ -92,18 +96,22 @@ class Telemetry:
         """Time one driver round. ``compile_expected`` marks rounds whose
         ``round_fn`` call will trace+compile (first execution of a jit
         variant): their wall time lands in ``compile_s``, steady-state
-        rounds in ``exec_s``."""
+        rounds in ``exec_s``. The round is a ``round`` profiler
+        annotation, and the spans inside it carry its index."""
         rec = {"type": "round", "round": int(t),
                "compile": bool(compile_expected), "phases": {}}
         self._current = rec
-        t0 = time.perf_counter()
-        try:
-            yield rec
-        finally:
-            rec["wall_s"] = time.perf_counter() - t0
-            self._current = None
-            self.rounds.append(rec)
-            self.sink.emit({"label": self.config.label, **rec})
+        self.trace.round = int(t)
+        with TraceAnnotation("round", round=int(t)):
+            t0 = time.perf_counter()
+            try:
+                yield rec
+            finally:
+                rec["wall_s"] = time.perf_counter() - t0
+                self._current = None
+                self.trace.round = None
+                self.rounds.append(rec)
+                self.sink.emit({"label": self.config.label, **rec})
 
     def annotate(self, **fields) -> None:
         """Merge fields into the live round record (sessions report

@@ -81,8 +81,9 @@ def _flens():
     ],
 )
 def test_null_sink_bit_identical(small_problem, comm_fn, tmp_path):
-    """Telemetry (null sink and jsonl sink alike) must not perturb the
-    trajectory on any driver: same losses, same grads, same bytes."""
+    """Telemetry (null sink and jsonl sink alike, and with its spans
+    annotating a running profiler) must not perturb the trajectory on
+    any driver: same losses, same grads, same bytes."""
     prob, w0, w_star = small_problem
     bare = run_rounds(_flens(), prob, w0, w_star, rounds=4, comm=comm_fn())
     null = run_rounds(_flens(), prob, w0, w_star, rounds=4, comm=comm_fn(),
@@ -90,7 +91,10 @@ def test_null_sink_bit_identical(small_problem, comm_fn, tmp_path):
     jsonl = run_rounds(
         _flens(), prob, w0, w_star, rounds=4, comm=comm_fn(),
         obs=TelemetryConfig(sink=f"jsonl:{tmp_path / 'tel.jsonl'}"))
-    for instrumented in (null, jsonl):
+    with jax.profiler.trace(str(tmp_path / "trace")):
+        profiled = run_rounds(_flens(), prob, w0, w_star, rounds=4,
+                              comm=comm_fn(), obs=TelemetryConfig())
+    for instrumented in (null, jsonl, profiled):
         assert np.array_equal(bare.loss, instrumented.loss)
         assert np.array_equal(bare.grad_norm, instrumented.grad_norm)
         assert np.array_equal(bare.cumulative_bytes,
@@ -114,6 +118,128 @@ def test_profiler_hook_raises_when_trace_cannot_start(small_problem,
                                            profile_dir=str(tmp_path / "p")))
     finally:
         jax.profiler.stop_trace()
+
+
+def test_profile_window_skips_the_compile_round(small_problem, tmp_path):
+    """``profile_rounds`` traces N rounds from the first that does not
+    compile: the trace's ``round`` annotations are rounds 1 and 2."""
+    prob, w0, w_star = small_problem
+    run_rounds(_flens(), prob, w0, w_star, rounds=4, comm=CommConfig(seed=1),
+               obs=TelemetryConfig(profile_rounds=2,
+                                   profile_dir=str(tmp_path / "p")))
+    rounds = {s["round"] for s in _host_spans(tmp_path / "p")
+              if s["name"] == "round"}
+    assert rounds == {1, 2}
+
+
+# ---------------------------------------------------------------------------
+# session spans, round calls, and the spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+SESSION_PHASES = {
+    "sync": {"session.schedule", "launch", "session.stats",
+             "session.account"},
+    "async": {"session.pump", "session.schedule", "launch", "session.stats",
+              "session.aggregate", "session.account", "session.gc",
+              "session.dispatch"},
+}
+
+
+def _async_comm():
+    return CommConfig(seed=1, async_mode=True, buffer_size=3,
+                      channel=ChannelModel(straggler_prob=0.5,
+                                           straggler_slowdown=6.0),
+                      staleness="inverse")
+
+
+def _round_records(path):
+    with open(path) as f:
+        return [r for r in map(json.loads, f) if r["type"] == "round"]
+
+
+@pytest.mark.parametrize("driver", ["sync", "async"])
+def test_round_records_carry_session_phases_and_round_calls(
+        small_problem, driver, tmp_path):
+    """Every round record splits its ``step`` into the session's phases,
+    ``launch`` and ``wait``, and counts its jitted round launches: one a
+    sync round, one per base model version in an async commit."""
+    prob, w0, w_star = small_problem
+    comm = CommConfig(seed=1) if driver == "sync" else _async_comm()
+    path = tmp_path / "tel.jsonl"
+    hist = run_rounds(_flens(), prob, w0, w_star, rounds=8, comm=comm,
+                      obs=TelemetryConfig(sink=f"jsonl:{path}"))
+    records = _round_records(path)
+    assert len(records) == len(hist.traces) == 8
+    for rec, tr in zip(records, hist.traces):
+        assert SESSION_PHASES[driver] | {"step", "wait", "eval"} <= set(
+            rec["phases"])
+        if driver == "sync":
+            assert rec["round_calls"] == 1
+        else:
+            bases = {tr.version - 1 - s for s in tr.staleness[tr.delivered]}
+            assert rec["round_calls"] == len(bases)
+    calls = [r["round_calls"] for r in records]
+    assert hist.telemetry["metrics"]["counters"]["round_calls"] == sum(calls)
+    if driver == "async":
+        assert max(calls) > 1  # stale groups re-run the round
+
+
+def _host_spans(trace_dir):
+    """The ``repro.obs`` spans on the host plane of the newest trace in
+    ``trace_dir``: name, thread, start, end (ns), round."""
+    from jax.profiler import ProfileData
+
+    path = max(trace_dir.rglob("*.xplane.pb"), key=lambda p: p.stat().st_mtime)
+    names = {"round", "prepare", "begin_variant", "probe_plan", "step",
+             "launch", "wait", "eval", "finalize"}
+    spans = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in names or e.name.startswith("session."):
+                    spans.append({"name": e.name, "line": line.name,
+                                  "start": e.start_ns,
+                                  "end": e.start_ns + e.duration_ns,
+                                  "round": dict(e.stats).get("round")})
+    return spans
+
+
+@pytest.mark.parametrize("driver", ["sync", "async"])
+def test_spans_reach_the_profiler_and_nest(small_problem, driver, tmp_path):
+    """Under a running profiler every span lands on its host plane with
+    its round: spans nest without overlap, the session's phases,
+    ``launch`` and ``wait`` sit directly under ``step``, and ``step``
+    and ``eval`` under their ``round``."""
+    prob, w0, w_star = small_problem
+    comm = CommConfig(seed=1) if driver == "sync" else _async_comm()
+    with jax.profiler.trace(str(tmp_path)):
+        run_rounds(_flens(), prob, w0, w_star, rounds=4, comm=comm,
+                   obs=TelemetryConfig())
+    spans = sorted(_host_spans(tmp_path),
+                   key=lambda s: (s["start"], -s["end"]))
+    assert {s["name"] for s in spans} >= SESSION_PHASES[driver] | {
+        "round", "step", "wait", "eval", "prepare", "finalize"}
+    assert len({s["line"] for s in spans}) == 1
+    stack, parent = [], {}
+    for s in spans:
+        while stack and stack[-1]["end"] <= s["start"]:
+            stack.pop()
+        if stack:
+            assert s["end"] <= stack[-1]["end"], (stack[-1], s)  # nested
+        parent[id(s)] = stack[-1]["name"] if stack else None
+        stack.append(s)
+    for s in spans:
+        if s["name"].startswith("session.") or s["name"] in ("launch",
+                                                             "wait"):
+            assert parent[id(s)] == "step", s
+        if s["name"] in ("step", "eval"):
+            assert parent[id(s)] == "round", s
+        if parent[id(s)] is not None:
+            assert s["round"] is not None
+    assert sorted(s["round"] for s in spans if s["name"] == "round") == [
+        0, 1, 2, 3]
 
 
 def test_summary_compile_exec_split(small_problem):
