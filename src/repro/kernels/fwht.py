@@ -12,6 +12,14 @@ factors ride along as (tiny) kernel inputs. For N <= 128 the row fits one
 lane tile and the transform is the single matmul X @ H_N (Mosaic cannot
 reshape a row narrower than a lane tile).
 
+The row block is chosen from the call's shapes (``srht_block_rows``):
+the most rows whose blocks and f32 intermediates fit half the kernels'
+VMEM limit, so a narrow row runs thousands of rows a grid step and the
+widest rows fall back to 8. Every body works row by row, so the grid is
+``cdiv(rows, block)`` with a ragged last block: the rows it reads past
+the array's end reach no real row, and its writes past the end are
+dropped. No row is padded in HBM.
+
 The compiled path takes 32- and 16-bit floats only (``check_compiled``);
 index maps return int32 so the kernels lower with x64 on or off.
 
@@ -77,6 +85,39 @@ def row_block(i):
     return i, jnp.int32(0)
 
 
+# f32 arrays of a block's width that a kernel body holds at once: the
+# signed input, the two factor matmuls, the one-hot matmul, the scaled
+# output and the operands split for an EXACT matmul
+_F32_TEMPS = 6
+
+
+def srht_block_rows(rows: int, n: int, k: int = 0,
+                    dtype=jnp.float32) -> int:
+    """Rows per grid step of a row-tiled transform kernel over (rows, n).
+
+    The largest multiple of the dtype's sublane count whose VMEM
+    footprint fits half of ``COMPILER_PARAMS``' limit (the rest is the
+    compiler's own scratch), capped at ``rows`` rounded up to it, and
+    never below it. Every array is counted at its lane-padded width,
+    ``max(n, 128)`` f32 lanes: the double-buffered input and output
+    blocks and ``_F32_TEMPS`` intermediates per row; once per call, the
+    in-kernel (n, k) one-hot selection (``k`` 0 where there is none),
+    the signs and the Hadamard factors. Where more than one block is
+    needed the blocks are balanced, so the last is not a sliver.
+    """
+    sub = 8 * 4 // jnp.dtype(dtype).itemsize
+    lanes = max(n, 128)
+    a, b = _factor(n)
+    fixed = 4 * (max(n, 8) * max(k, 128) if k else 0)
+    fixed += 2 * 4 * (8 * lanes + 8 * 128 + max(a, 8) * max(a, 128)
+                      + b * max(b, 128))
+    per_row = (2 + 2 + _F32_TEMPS) * 4 * lanes
+    budget = COMPILER_PARAMS.vmem_limit_bytes // 2 - fixed
+    most = max(sub, budget // per_row // sub * sub)
+    steps = max(1, pl.cdiv(rows, most))
+    return max(sub, pl.cdiv(pl.cdiv(rows, steps), sub) * sub)
+
+
 def whole_block(i):
     """Index map of an operand every grid step reads whole."""
     del i
@@ -106,27 +147,26 @@ def _fwht_kernel(x_ref, ha_ref, hb_ref, o_ref, *, a: int, b: int, norm: float):
 
 
 @functools.partial(jax.jit, static_argnames=("normalize", "block_rows", "interpret"))
-def fwht_pallas(x: jax.Array, *, normalize: bool = False, block_rows: int = 8,
+def fwht_pallas(x: jax.Array, *, normalize: bool = False,
+                block_rows: int | None = None,
                 interpret: bool = False) -> jax.Array:
-    """WHT along the last axis. x (..., N), N a power of two."""
+    """WHT along the last axis. x (..., N), N a power of two.
+
+    ``block_rows`` overrides the shape-chosen row block."""
     check_compiled(x, interpret=interpret)
     orig_shape = x.shape
     n = orig_shape[-1]
     a, b = _factor(n)
-    rows = 1
-    for d in orig_shape[:-1]:
-        rows *= d
-    xm = x.reshape(rows, n)
-    pad = (-rows) % block_rows
-    if pad:
-        xm = jnp.pad(xm, ((0, pad), (0, 0)))
+    xm = x.reshape(-1, n)
+    rows = xm.shape[0]
+    block_rows = block_rows or srht_block_rows(rows, n, 0, x.dtype)
     ha = hadamard_matrix(a, jnp.float32)
     hb = hadamard_matrix(b, jnp.float32)
     norm = (1.0 / n**0.5) if normalize else 1.0
 
     out = pl.pallas_call(
         functools.partial(_fwht_kernel, a=a, b=b, norm=norm),
-        grid=(xm.shape[0] // block_rows,),
+        grid=(pl.cdiv(rows, block_rows),),
         in_specs=[
             pl.BlockSpec((block_rows, n), row_block),
             pl.BlockSpec((a, a), whole_block),
@@ -137,6 +177,4 @@ def fwht_pallas(x: jax.Array, *, normalize: bool = False, block_rows: int = 8,
         compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(xm, ha, hb)
-    if pad:
-        out = out[:rows]
     return out.reshape(orig_shape)

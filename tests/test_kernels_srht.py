@@ -3,9 +3,12 @@
 The kernel body runs in interpret mode (CPU CI); ``impl="ref"`` is the
 pure-jnp oracle every golden trajectory is pinned to. Parity covers
 pow2/non-pow2 dims, fp32/bf16, forward and transpose, batched/vmapped
-callers, and the redesigned ``repro.kernels.ops`` selection API
-(per-call > config > env > auto).
+callers, row counts whose last block is ragged, the shape-chosen row
+block against the 8-row block, and the redesigned ``repro.kernels.ops``
+selection API (per-call > config > env > auto).
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,6 +17,8 @@ import pytest
 from repro.core.sketch import SrhtSketch, make_sketch
 from repro.kernels import ops as kops
 from repro.kernels import ref
+from repro.kernels.fwht import COMPILER_PARAMS, _F32_TEMPS, srht_block_rows
+from repro.kernels.srht import srht_apply_pallas, srht_apply_t_pallas
 
 
 def _srht(dim, k=8, dtype=jnp.float32, seed=0):
@@ -28,26 +33,44 @@ def _tol(n, dtype):
     return dict(rtol=2e-4, atol=2e-4 * n ** 0.5)
 
 
+# (rows, block_rows): 5 rows fit one block; 1003 rows take one block
+# that overhangs the array by 5, or, at an explicit 64, sixteen blocks
+# whose last is ragged (43 rows)
+ROW_BLOCKS = [(5, None), (1003, None), (1003, 64)]
+
+
+def _interpret(op, block_rows):
+    """The interpret-mode kernel: through the dispatch at the chosen
+    block, or straight at an explicit ``block_rows``."""
+    if block_rows is None:
+        return functools.partial(getattr(kops, op), impl="interpret")
+    fn = {"srht_apply": srht_apply_pallas,
+          "srht_apply_t": srht_apply_t_pallas}[op]
+    return functools.partial(fn, block_rows=block_rows, interpret=True)
+
+
+@pytest.mark.parametrize("rows,block_rows", ROW_BLOCKS)
 @pytest.mark.parametrize("dim", [16, 24, 37, 64, 100, 256])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_srht_forward_parity(dim, dtype):
+def test_srht_forward_parity(dim, dtype, rows, block_rows):
     s = _srht(dim, dtype=dtype)
-    x = jax.random.normal(jax.random.PRNGKey(1), (5, dim), dtype)
+    x = jax.random.normal(jax.random.PRNGKey(1), (rows, dim), dtype)
     want = kops.srht_apply(x, s.signs, s.rows, impl="ref")
-    got = kops.srht_apply(x, s.signs, s.rows, impl="interpret")
+    got = _interpret("srht_apply", block_rows)(x, s.signs, s.rows)
     n = s.signs.shape[-1]
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **_tol(n, dtype))
 
 
+@pytest.mark.parametrize("rows,block_rows", ROW_BLOCKS)
 @pytest.mark.parametrize("dim", [16, 24, 37, 64, 100, 256])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_srht_transpose_parity(dim, dtype):
+def test_srht_transpose_parity(dim, dtype, rows, block_rows):
     s = _srht(dim, dtype=dtype)
-    y = jax.random.normal(jax.random.PRNGKey(2), (5, s.k), dtype)
+    y = jax.random.normal(jax.random.PRNGKey(2), (rows, s.k), dtype)
     want = kops.srht_apply_t(y, s.signs, s.rows, dim, impl="ref")
-    got = kops.srht_apply_t(y, s.signs, s.rows, dim, impl="interpret")
-    assert got.shape == (5, dim)
+    got = _interpret("srht_apply_t", block_rows)(y, s.signs, s.rows, dim)
+    assert got.shape == (rows, dim)
     n = s.signs.shape[-1]
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **_tol(n, dtype))
@@ -79,14 +102,84 @@ def test_srht_batched_shapes(shape):
     np.testing.assert_allclose(got, want, **_tol(s.signs.shape[-1], jnp.float32))
 
 
-def test_srht_vmap_through_dispatch():
-    """jax.vmap(s.apply) is how every optimizer maps clients; both impls
-    must batch."""
+@pytest.mark.parametrize("slab", [(), (37,), (277,)])
+@pytest.mark.parametrize("transpose", [False, True])
+def test_srht_vmap_through_dispatch(slab, transpose):
+    """jax.vmap(s.apply) is how every optimizer maps clients — over
+    gradient vectors and over (rows, dim) slabs, 277 rows as a phishing
+    client holds; both impls must batch, and the interpret kernel under
+    vmap must match ``ref``."""
     s = _srht(24)
-    g = jax.random.normal(jax.random.PRNGKey(5), (6, 24), jnp.float32)
-    want = jax.vmap(s.apply)(g)
-    got = jax.vmap(lambda x: s.apply(x, impl="interpret"))(g)
+    width = s.k if transpose else 24
+    g = jax.random.normal(jax.random.PRNGKey(5), (6, *slab, width),
+                          jnp.float32)
+    if transpose:
+        want = jax.vmap(lambda y: ref.srht_apply_t(y, s.signs, s.rows, 24))(g)
+        auto = jax.vmap(s.apply_t)(g)
+        got = jax.vmap(lambda y: s.apply_t(y, impl="interpret"))(g)
+    else:
+        want = jax.vmap(lambda x: ref.srht_apply(x, s.signs, s.rows))(g)
+        auto = jax.vmap(s.apply)(g)
+        got = jax.vmap(lambda x: s.apply(x, impl="interpret"))(g)
+    np.testing.assert_allclose(auto, want, **_tol(32, jnp.float32))
     np.testing.assert_allclose(got, want, **_tol(32, jnp.float32))
+
+
+@pytest.mark.parametrize("rows", [1, 37, 277, 1003, 5000])
+@pytest.mark.parametrize("transpose", [False, True])
+def test_srht_chosen_block_matches_8_row_blocks(rows, transpose):
+    """The shape-chosen block (one overhanging block, or balanced blocks
+    with a ragged last one) gives the 8-row blocks' result. On the chip
+    the two are bit-equal; the CPU's dot sums in an order that depends
+    on the block's row count, hence a few float32 ulps."""
+    dim, k = 18, 10
+    s = _srht(dim, k=k)
+    if transpose:
+        v = jax.random.normal(jax.random.PRNGKey(14), (rows, k), jnp.float32)
+        run = functools.partial(srht_apply_t_pallas, v, s.signs, s.rows,
+                                dim, interpret=True)
+    else:
+        v = jax.random.normal(jax.random.PRNGKey(14), (rows, dim),
+                              jnp.float32)
+        run = functools.partial(srht_apply_pallas, v, s.signs, s.rows,
+                                interpret=True)
+    got = np.asarray(run())
+    want = np.asarray(run(block_rows=8))
+    np.testing.assert_allclose(
+        got, want, rtol=0,
+        atol=16 * np.finfo(np.float32).eps * np.abs(want).max())
+
+
+@pytest.mark.parametrize("rows", [1, 7, 37, 277, 1003, 5000, 10 ** 6])
+@pytest.mark.parametrize("n,k", [(16, 8), (32, 10), (128, 17), (2048, 64),
+                                 (2048, 1024), (8192, 128), (8192, 0),
+                                 (65536, 0)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_srht_block_rows_fits_and_is_capped(rows, n, k, dtype):
+    """The helper's block: a multiple of the dtype's sublane count, never
+    past the rows rounded up to it, and (above the 8-row floor) blocks
+    and intermediates inside the kernels' VMEM limit."""
+    sub = 8 if dtype == jnp.float32 else 16
+    block = srht_block_rows(rows, n, k, dtype)
+    assert block % sub == 0 and block >= sub
+    assert block <= -(-rows // sub) * sub
+    lanes = max(n, 128)
+    if block > sub:
+        per_row = (4 + _F32_TEMPS) * 4 * lanes
+        assert block * per_row <= COMPILER_PARAMS.vmem_limit_bytes
+
+
+def test_srht_block_rows_at_the_cells_shapes():
+    """The blocks the benchmark cells run: a SUSY client's 5000 rows at
+    n = 32, k = 10 in a few large blocks (was 625 blocks of 8); a
+    phishing client's 277 rows at n = 128, k = 17 in one; FedNS's
+    data-axis sketch at n = 8192 stays at small blocks."""
+    susy = srht_block_rows(5000, 32, 10)
+    assert susy >= 512
+    assert -(-5000 // susy) <= 10
+    assert -(-277 // srht_block_rows(277, 128, 17)) == 1
+    assert srht_block_rows(10 ** 6, 8192, 128) <= 64
+    assert srht_block_rows(10 ** 6, 65536, 0) == 8
 
 
 def test_srht_sketch_matches_dense_through_interpret():

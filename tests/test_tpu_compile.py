@@ -13,6 +13,7 @@ may load the TPU library, so describing it on import would break every
 other test worker.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +34,9 @@ from repro.sharding.rules import client_mesh_scope
 WIDTHS = (16, 32, 128, 2048, 65536)
 CODEC_SIZES = (68, 2000, 65536)
 ROWS = 16
+# a round's per-client sketch A_j S^T in the benchmark's cells:
+# (clients, rows per client, d, k, n) for SUSY and phishing
+CELL_SKETCHES = [(1000, 5000, 18, 10, 32), (40, 277, 68, 17, 128)]
 
 
 @pytest.fixture(scope="module")
@@ -99,6 +103,20 @@ def test_srht_apply_t_compiles(one_chip, n):
     _compile(lambda y, s, r: srht_apply_t_pallas(y, s, r, n),
              _spec(one_chip, (ROWS, k)), _spec(one_chip, (n,)),
              _spec(one_chip, (k,), jnp.int32))
+
+
+@pytest.mark.parametrize("clients,rows,dim,k,n", CELL_SKETCHES)
+def test_vmapped_srht_compiles_at_cell_shapes_without_pads(
+        one_chip, clients, rows, dim, k, n):
+    """The kernel vmapped over clients as the round maps it, at the
+    shape-chosen row block: it reads the caller's (clients, rows, d)
+    array as it is and writes (clients, rows, k), so the program pads
+    the operand neither along its rows nor along its lanes."""
+    hlo = _compile(jax.vmap(srht_apply_pallas, in_axes=(0, None, None)),
+                   _spec(one_chip, (clients, rows, dim)),
+                   _spec(one_chip, (n,)), _spec(one_chip, (k,), jnp.int32))
+    assert not re.search(r" pad\(", hlo)
+    assert f"f32[{clients},{rows},{k}]" in hlo
 
 
 @pytest.mark.parametrize("size", CODEC_SIZES)
