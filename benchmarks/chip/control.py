@@ -6,8 +6,9 @@ benchmark run.
         [--control-seeds N]
 
 After one set-up of the cell (data, problem), each seed gets one
-``run_rounds`` call of ``R`` rounds, and for each variant one
-JSON line with the numbers ``correct`` compares:
+``run_rounds`` call of ``R`` rounds under the seed a benchmark run would
+drive (``harness.run_seed``), and for each variant one JSON line with
+the numbers ``correct`` compares:
 
 * ``program``: the call itself against the float32 reference (a sound
   run: its numbers set the lower reading);
@@ -50,18 +51,20 @@ def readings(name: str, seeds, *, variants=VARIANTS, rounds: int = 0,
     system = harness.build_system(cell, X, y)
     rounds = rounds or int(cell.traffic["compare_steps"]) + 2
     for i, seed in enumerate(seeds):
-        hist, t_ret = harness.run_call(system, rounds, seed, records)
+        seed_run = harness.run_seed(cell, seed, rounds)
+        hist, t_ret = harness.run_call(system, rounds, seed_run, records)
         call = harness.read_call(hist, t_ret, records)
         for variant in variants:
             if (variant != "program" and control_seeds is not None
                     and i >= control_seeds):
                 continue
             numbers = harness.check(
-                cell, call, X, y, seed,
+                cell, call, X, y, seed_run,
                 dtype=jnp.bfloat16 if variant == "bf16" else None,
                 fault=variant if variant in ("unchanged", "half") else None,
                 per_round=True)
-            yield {"workload": name, "seed": seed, "variant": variant,
+            yield {"workload": name, "seed": seed, "run_seed": seed_run,
+                   "variant": variant,
                    "device": dev.device_kind, **numbers}
 
 

@@ -4,14 +4,23 @@ A cell (an entry of ``workloads`` in ``BENCHMARK.json``) names a
 configuration and a traffic mix; both are data files found by name:
 
 * ``configs/<config>.json``: the dataset twin's sizes (rows, features,
-  Table II's clients and sketch size), its spectrum and label noise,
-  the ridge weight and the seed of the fixed dataset;
+  clients and sketch size), its spectrum and label noise, the ridge
+  weight and the seed of the fixed dataset;
 * ``workloads/<traffic>.json``: the driver (sync or async), codecs,
   channel, the round rate the window is sized for, and the round counts
   of the traced call and the comparison;
 * ``limits/<cell>.json``: the limit of each number ``correct`` is
   decided on;
 * ``metrics/<metric>.py``: one reader per per-layer metric.
+
+A configuration from outside the paper's Table II needs no code: a
+``configs/<config>.json`` that names its source and states
+``published``, ``published_sizes`` (the source's numbers), ``assumed``
+(every other number the harness reads, with its reason) and
+``reduced`` (each number cut below the published one), a traffic file
+where no existing one fits, ``limits/<cell>.json``, and the entries of
+``BENCHMARK.json``: the configuration, the cell, and the cell's name in
+the ``workloads`` of each per-layer metric it reports.
 
 The timed path is the program's public API as its users drive it:
 ``make_problem``, ``newton_solve``, ``make_optimizer``, ``CommConfig``
@@ -29,7 +38,9 @@ then carries the per-layer metrics.
 ``correct``: the reference (``reference.py``) follows the call's first
 ``compare_steps`` rounds on the schedule the configuration asks for;
 every round's billed wire bytes and who delivered in it are compared
-with that schedule; see ``check``.
+with that schedule; see ``check``. The call runs under the first seed
+from ``--seed`` on whose compared rounds the sketch has full rank
+(``run_seed``), which the result line reports.
 """
 from __future__ import annotations
 
@@ -382,6 +393,42 @@ def check(cell: Cell, call: Call, X, y, seed: int, dtype=None,
     return numbers
 
 
+def run_seed(cell: Cell, seed: int, rounds: int) -> int:
+    """The seed a run drives the program with: ``seed`` modulo 2**32, or
+    the first after it whose compared rounds each draw a sketch of full
+    rank, min(``sketch_k``, ``dim``). Where ``dim`` is below its power
+    of two n, the SRHT's k rows of H_n can agree on the first ``dim``
+    columns (SUSY: a quarter of rounds); the server's damped solve of
+    such a sketch takes a step that rounding sets, and the float32
+    reference with each client's rows reversed disagrees with itself by
+    as much as the bfloat16 control. So every run compares work of one
+    difficulty; the rounds after the compared ones draw their sketches
+    as they come. ``rounds`` is the call's round count, which splits
+    the run's key. The draws run on the host's CPU where JAX has one, so
+    that no program of theirs counts in the chip's peak memory."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.chip import reference
+
+    k, dim = int(cell.config["sketch_k"]), int(cell.config["dim"])
+    steps = int(cell.traffic["compare_steps"])
+    try:
+        host = jax.local_devices(backend="cpu")[0]
+    except RuntimeError:
+        host = None
+    s = int(seed) % (1 << 32)
+    with jax.default_device(host):
+        while True:
+            keys = jax.random.split(jax.random.PRNGKey(s), rounds)
+            ranks = [np.linalg.matrix_rank(np.asarray(reference.srht_matrix(
+                keys[t], k, dim, jnp.float32), np.float64))
+                for t in range(steps)]
+            if min(ranks) == min(k, dim):
+                return s
+            s = (s + 1) % (1 << 32)
+
+
 # ---------------------------------------------------------------------------
 # one run
 # ---------------------------------------------------------------------------
@@ -439,10 +486,11 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     devices, peaks = find_chips(cell.chips)
     _compile_cache()
     dev = devices[0]
-    run_seed = int(seed) % (1 << 32)
     out = ROOT / ".bench_out" if out is None else out
     records = out / f"{name}.rounds.jsonl"
     tr = cell.traffic
+    rounds = int(tr["trace_rounds"]) if trace else window_rounds(tr, seconds)
+    seed_run = run_seed(cell, seed, rounds)
 
     X, y = make_data(cell.config)
     system = build_system(cell, X, y)
@@ -452,18 +500,15 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
         # an asynchronous mix's first commits of stale groups compile the
         # driver's eager delta arithmetic: a short call runs them first
         run_call(system, max(warmup, int(tr["compare_steps"]) + 1),
-                 run_seed, records)
+                 seed_run, records)
     if trace:
         import shutil
 
-        rounds = int(tr["trace_rounds"])
         shutil.rmtree(trace_dir, ignore_errors=True)
         jax.profiler.start_trace(str(trace_dir))
-    else:
-        rounds = window_rounds(tr, seconds)
     try:
         with CompileClock() as clock:
-            hist, t_ret = run_call(system, rounds, run_seed, records)
+            hist, t_ret = run_call(system, rounds, seed_run, records)
     finally:
         if trace:
             jax.profiler.stop_trace()
@@ -513,11 +558,12 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
 
     jax.clear_caches()
     gc.collect()
-    numbers = check(cell, call, X, y, run_seed)
+    numbers = check(cell, call, X, y, seed_run)
     checks = {k: {"value": v, "limit": cell.limits[k]}
               for k, v in numbers.items()}
     result["correct"] = bool(failed == 0 and all(
         c["value"] <= c["limit"] for c in checks.values()))
+    result["run_seed"] = seed_run
     result["checks"] = checks
     return result
 

@@ -4,10 +4,10 @@ chip run executes, with the CPU taken for the chip (``cpu_harness``) and
 the reference ops, as on any CPU."""
 import pytest
 
-from benchmarks.chip import harness, xplane
+from benchmarks.chip import harness
 
-from _bench_common import (CELLS, CHECKS, END_TO_END, MANIFEST, run,
-                           synthetic_trace_text, write_trace)
+from _bench_common import (CELLS, CHECKS, END_TO_END, MANIFEST, SIZES,
+                           check_traced_run, run)
 from _bench_common import cpu_harness  # noqa: F401 — a fixture
 
 
@@ -28,20 +28,11 @@ def test_cell_runs_end_to_end_and_is_correct(name, tmp_path, cpu_harness):
 @pytest.mark.parametrize("name", CELLS)
 def test_traced_run_reads_every_listed_metric(name, tmp_path, cpu_harness,
                                               monkeypatch):
-    """A CPU trace has no TPU plane: the reduction reads a TPU trace
-    whose kernels carry the names the chip's do, and every per-layer
-    metric the manifest lists for the cell comes out, with the
-    breakdown."""
-    trace = write_trace(tmp_path / "t.xplane.pb.gz", synthetic_trace_text())
-    monkeypatch.setattr(xplane, "newest_xplane", lambda d: trace)
-    result = run(name, tmp_path, trace=True)
-    assert result["correct"] is True, result["checks"]
+    """Every per-layer metric the manifest lists for the cell comes out
+    of a traced run at the cell's CPU size (``check_traced_run``)."""
     listed = {m["name"] for m in MANIFEST["per_layer"]
               if name in m["workloads"]}
-    assert set(result["metrics"]) == listed
-    assert all(0 < v["value"] < 100 for v in result["metrics"].values())
-    assert 0 < result["device"]["busy_s"] < result["device"]["window_s"]
-    assert result["breakdown"]["device_ops"]
+    check_traced_run(name, tmp_path, monkeypatch, SIZES[name], listed)
 
 
 def test_window_rounds_follow_the_stated_rate():

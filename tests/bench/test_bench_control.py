@@ -18,7 +18,7 @@ from _bench_common import cpu_harness  # noqa: F401 — a fixture
 def test_control_and_faults_fail_the_limits(name, tmp_path, cpu_harness):
     limits = harness.load_cell(name).limits
     got = {}
-    for rec in control.readings(name, [SEED], sizes=SIZES,
+    for rec in control.readings(name, [SEED], sizes=SIZES[name],
                                 out=tmp_path):
         got[rec["variant"]] = rec
     for key in CHECKS:
